@@ -1,8 +1,8 @@
 """Kernel-layer microbenchmarks.
 
-NOTE: this container executes Pallas in interpret mode on one CPU core, so
-wall times here are NOT TPU predictions — the TPU-facing numbers live in the
-dry-run roofline (§Roofline).  What this benchmark DOES establish on CPU:
+NOTE: on the CPU backend Pallas runs in interpret mode, so wall times there
+are NOT TPU predictions — a device time comes only from a run on the chip.
+What this benchmark DOES establish on CPU:
 
 * the blocked layout's arithmetic-intensity advantage, reported as modeled
   FLOPs-per-HBM-byte for bbmv (contiguous block-banded) vs spmv_ell
@@ -38,10 +38,9 @@ from repro.core import (BlockBandedOp, CsrOp, EllOp, block_banded_spd,
                         random_sparse_spd)
 from repro.core.engine import solve_sequential
 from repro.kernels import ops, ref
-# The roofline terms come from repro.roofline — the same hardware model
-# solver_roofline.py's dry-run analysis reads — so the two reports cannot
-# drift apart.  The peaks are the TPU-v5e model; on CPU interpret mode the
-# fractions are honest near-zeros and the provenance stamp says why.
+# The roofline terms come from repro.roofline's hardware model.  The peaks
+# are the TPU-v5e model; on CPU interpret mode the fractions are honest
+# near-zeros and the provenance stamp says why.
 from repro.roofline import HBM_BW, PEAK_FLOPS
 
 

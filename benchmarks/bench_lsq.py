@@ -20,17 +20,13 @@ method never needs.
 
 A full run persists its records (wall-clock, relresid trajectories, problem
 dims, P/tau) to BENCH_lsq.json at the repo root so later PRs can diff the
-perf trajectory.  The ``overlap_tau`` section (``run_overlap_tau``, forced
-4-device subprocess) records scheduled vs measured staleness for the
-overlapped-sync variants and the theory quantities at both.
+perf trajectory.  The ``overlap_tau`` section (``run_overlap_tau``)
+records scheduled vs measured staleness for the overlapped-sync variants
+and the theory quantities at both; it and ``run_precision`` run in this
+process on a 4-device mesh, so on the CPU backend start with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 """
 from __future__ import annotations
-
-import json
-import os
-import subprocess
-import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -38,9 +34,10 @@ import numpy as np
 
 import time
 
-from benchmarks.common import emit, timed, write_json
+from benchmarks.common import emit, timed, worker_mesh, write_json
 from repro.core import (BlockBandedOp, CsrOp, Schedule, block_banded_spd,
-                        cg_solve, random_lsq, random_sparse_lsq, rk_solve,
+                        cg_solve, random_lsq, random_sparse_lsq,
+                        random_sparse_spd, rk_solve,
                         solve, theory, to_unit_diagonal)
 from repro.core.engine import scheduled_tau, solve_distributed
 from repro.launch.mesh import make_host_mesh
@@ -278,79 +275,51 @@ def run_partitioned_rk(m: int = 2048, n: int = 512, row_nnz: int = 16,
     return out
 
 
-_OVERLAP_TAU_SCRIPT = """
-import json
-import jax, jax.numpy as jnp, numpy as np
-from repro.core import CsrOp, random_sparse_spd, theory
-from repro.core.engine import scheduled_tau, solve_distributed
-from repro.launch.mesh import make_host_mesh
-
-P, L, rounds = {workers}, {local_steps}, {rounds}
-prob = random_sparse_spd({n}, row_nnz={row_nnz}, n_rhs={rhs}, seed={seed})
-op = CsrOp.from_dense(prob.A)
-x0 = jnp.zeros_like(prob.x_star)
-mesh = make_host_mesh(P)
-rho = float(theory.rho(prob.A))
-out = {{"workers": P, "local_steps": L, "rounds": rounds, "rho": rho}}
-for action, fused in (("gs", True), ("rk", True)):
-    local_sampling = action == "rk"
-    tau_lock = scheduled_tau(P, L, local_sampling=local_sampling)
-    res = {{}}
-    for overlap in (False, True):
-        r = solve_distributed(op, prob.b, x0, prob.x_star, action=action,
-                              key=jax.random.key(1), mesh=mesh,
-                              rounds=rounds, local_steps=L, beta={beta},
-                              fused=fused, overlap=overlap)
-        jax.block_until_ready(r.x)
-        rec = {{"tau_scheduled": int(r.tau),
-               "err_first": float(r.err_sq[0].max()),
-               "err_last": float(r.err_sq[-1].max()),
-               "beta_opt": theory.beta_opt(rho, int(r.tau))}}
-        if overlap:
-            lag = np.asarray(r.lag)
-            # measured staleness: in-flight payload + lockstep interleave
-            rec["lag_trace_head"] = lag[:4].tolist()
-            rec["lag_steady"] = int(lag[1:].max()) if rounds > 1 else 0
-            rec["tau_empirical"] = int(lag.max()) + tau_lock
-            rec["bound_holds"] = rec["tau_empirical"] <= int(r.tau)
-            rec["beta_opt_empirical"] = theory.beta_opt(
-                rho, rec["tau_empirical"])
-            rec["nu_tau_at_beta"] = theory.nu_tau(rho, rec["tau_empirical"],
-                                                  {beta})
-        res["overlap" if overlap else "lockstep"] = rec
-    out[action] = res
-print("OVERLAP_TAU_JSON " + json.dumps(out))
-"""
-
-
 def run_overlap_tau(n: int = 256, row_nnz: int = 8, rhs: int = 4,
                     rounds: int = 30, local_steps: int = 8,
                     beta: float = 0.9, seed: int = 2, workers: int = 4):
-    """Scheduled vs measured staleness for the overlapped-sync variants
-    (ISSUE 6 tentpole): runs sparse GS / sparse RK lockstep and overlapped
-    on a forced-``workers``-device host mesh (fresh interpreter — XLA's
-    device count is fixed at import) and reports the per-round lag trace,
+    """Scheduled vs measured staleness for the overlapped-sync variants:
+    sparse GS / sparse RK lockstep and overlapped on a
+    ``workers``-device mesh, reporting the per-round lag trace,
     the empirical tau it implies, and the theory quantities
     (``beta_opt``, ``nu_tau``) at both the scheduled bound and the
     measured staleness.  The scheduled bound must dominate the measured
     trace — that is the contract the overlap term of ``scheduled_tau``
     encodes.
     """
-    script = ("import os\n"
-              f'os.environ["XLA_FLAGS"] = '
-              f'"--xla_force_host_platform_device_count={workers}"\n'
-              + _OVERLAP_TAU_SCRIPT.format(
-                  workers=workers, local_steps=local_steps, rounds=rounds,
-                  n=n, row_nnz=row_nnz, rhs=rhs, seed=seed, beta=beta))
-    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, "-c", script], env=env,
-                          capture_output=True, text=True, timeout=900)
-    if proc.returncode != 0:
-        raise RuntimeError(f"overlap-tau subprocess failed:\n"
-                           f"{proc.stderr[-3000:]}")
-    line = next(l for l in proc.stdout.splitlines()
-                if l.startswith("OVERLAP_TAU_JSON "))
-    out = json.loads(line[len("OVERLAP_TAU_JSON "):])
+    P, L = workers, local_steps
+    mesh = worker_mesh(P)
+    prob = random_sparse_spd(n, row_nnz=row_nnz, n_rhs=rhs, seed=seed)
+    op = CsrOp.from_dense(prob.A)
+    x0 = jnp.zeros_like(prob.x_star)
+    rho = float(theory.rho(prob.A))
+    out = {"workers": P, "local_steps": L, "rounds": rounds, "rho": rho}
+    for action in ("gs", "rk"):
+        tau_lock = scheduled_tau(P, L, local_sampling=action == "rk")
+        res = {}
+        for overlap in (False, True):
+            r = solve_distributed(op, prob.b, x0, prob.x_star, action=action,
+                                  key=jax.random.key(1), mesh=mesh,
+                                  rounds=rounds, local_steps=L, beta=beta,
+                                  fused=True, overlap=overlap)
+            jax.block_until_ready(r.x)
+            rec = {"tau_scheduled": int(r.tau),
+                   "err_first": float(r.err_sq[0].max()),
+                   "err_last": float(r.err_sq[-1].max()),
+                   "beta_opt": theory.beta_opt(rho, int(r.tau))}
+            if overlap:
+                lag = np.asarray(r.lag)
+                # measured staleness: in-flight payload + lockstep interleave
+                rec["lag_trace_head"] = lag[:4].tolist()
+                rec["lag_steady"] = int(lag[1:].max()) if rounds > 1 else 0
+                rec["tau_empirical"] = int(lag.max()) + tau_lock
+                rec["bound_holds"] = rec["tau_empirical"] <= int(r.tau)
+                rec["beta_opt_empirical"] = theory.beta_opt(
+                    rho, rec["tau_empirical"])
+                rec["nu_tau_at_beta"] = theory.nu_tau(
+                    rho, rec["tau_empirical"], beta)
+            res["overlap" if overlap else "lockstep"] = rec
+        out[action] = res
     for action in ("gs", "rk"):
         ov, lk = out[action]["overlap"], out[action]["lockstep"]
         if not ov["bound_holds"]:
@@ -370,44 +339,13 @@ def run_overlap_tau(n: int = 256, row_nnz: int = 8, rhs: int = 4,
     return out
 
 
-_PRECISION_SCRIPT = """
-import json
-import jax, jax.numpy as jnp, numpy as np
-from repro.core import CsrOp, random_sparse_lsq
-from repro.core.engine import solve_distributed
-from repro.launch.mesh import make_host_mesh
-
-P, L, rounds = {workers}, {local_steps}, {rounds}
-prob = random_sparse_lsq({m}, {n}, row_nnz={row_nnz}, n_rhs={rhs},
-                         seed={seed})
-op = CsrOp.from_dense(prob.A)
-x0 = jnp.zeros_like(prob.x_star)
-mesh = make_host_mesh(P)
-tol = {tol}
-out = {{"workers": P, "local_steps": L, "rounds": rounds, "tol": tol}}
-for compress in ("none", "bf16", "int8_ef"):
-    r = solve_distributed(op, prob.b, x0, prob.x_star, action="rk",
-                          key=jax.random.key(1), mesh=mesh, rounds=rounds,
-                          local_steps=L, beta={beta}, sync="psum",
-                          compress=compress)
-    err = np.asarray(r.err_sq).max(axis=1)
-    hit = np.nonzero(err <= tol * err[0])[0]
-    out[compress] = {{
-        "bytes_per_round": float(r.bytes_per_round),
-        "err_first": float(err[0]), "err_last": float(err[-1]),
-        "rounds_to_tol": int(hit[0]) + 1 if hit.size else 0,
-    }}
-print("PRECISION_JSON " + json.dumps(out))
-"""
-
-
 def run_precision(m: int = 512, n: int = 256, row_nnz: int = 6, rhs: int = 2,
                   rounds: int = 60, local_steps: int = 16, beta: float = 1.0,
                   tol: float = 0.05, seed: int = 3, workers: int = 4,
                   sweeps: int = 8):
-    """The precision trade-off, measured (ISSUE 7 tentpole).
+    """The precision trade-off, measured.
 
-    Wire: sparse-RK delta psum on a forced-``workers``-device mesh with
+    Wire: sparse-RK delta psum on a ``workers``-device mesh with
     ``compress`` ∈ {none, bf16, int8_ef} — per-mode bytes-per-round (the
     engine's analytic payload model), rounds to reach ``tol`` × the
     round-1 error, and the round inflation vs the exact f32 wire (the
@@ -419,22 +357,24 @@ def run_precision(m: int = 512, n: int = 256, row_nnz: int = 6, rhs: int = 2,
     the step, not the iterate), so the per-step contraction moves from
     ``c`` to ``c + eps*(1-c)`` and the predicted inflation stays finite.
     """
-    script = ("import os\n"
-              f'os.environ["XLA_FLAGS"] = '
-              f'"--xla_force_host_platform_device_count={workers}"\n'
-              + _PRECISION_SCRIPT.format(
-                  workers=workers, local_steps=local_steps, rounds=rounds,
-                  m=m, n=n, row_nnz=row_nnz, rhs=rhs, seed=seed, beta=beta,
-                  tol=tol))
-    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, "-c", script], env=env,
-                          capture_output=True, text=True, timeout=900)
-    if proc.returncode != 0:
-        raise RuntimeError(f"precision subprocess failed:\n"
-                           f"{proc.stderr[-3000:]}")
-    line = next(l for l in proc.stdout.splitlines()
-                if l.startswith("PRECISION_JSON "))
-    out = json.loads(line[len("PRECISION_JSON "):])
+    P, L = workers, local_steps
+    mesh = worker_mesh(P)
+    prob = random_sparse_lsq(m, n, row_nnz=row_nnz, n_rhs=rhs, seed=seed)
+    op = CsrOp.from_dense(prob.A)
+    x0 = jnp.zeros_like(prob.x_star)
+    out = {"workers": P, "local_steps": L, "rounds": rounds, "tol": tol}
+    for compress in ("none", "bf16", "int8_ef"):
+        r = solve_distributed(op, prob.b, x0, prob.x_star, action="rk",
+                              key=jax.random.key(1), mesh=mesh, rounds=rounds,
+                              local_steps=L, beta=beta, sync="psum",
+                              compress=compress)
+        err = np.asarray(r.err_sq).max(axis=1)
+        hit = np.nonzero(err <= tol * err[0])[0]
+        out[compress] = {
+            "bytes_per_round": float(r.bytes_per_round),
+            "err_first": float(err[0]), "err_last": float(err[-1]),
+            "rounds_to_tol": int(hit[0]) + 1 if hit.size else 0,
+        }
     base = out["none"]["rounds_to_tol"]
     if base == 0:
         raise RuntimeError(f"f32 wire never reached tol {tol}")
@@ -457,7 +397,6 @@ def run_precision(m: int = 512, n: int = 256, row_nnz: int = 6, rhs: int = 2,
             f"1.3x acceptance bound")
 
     # storage: f32 vs bf16 coefficient panels, sequential RK, equal work
-    prob = random_sparse_lsq(m, n, row_nnz=row_nnz, n_rhs=rhs, seed=seed)
     bn = float(jnp.linalg.norm(prob.b))
     floor = float(jnp.linalg.norm(prob.b - prob.A @ prob.x_star)) / bn
     storage = {}

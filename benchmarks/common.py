@@ -43,6 +43,19 @@ def provenance() -> dict:
             "interpret_mode": interpret_default()}
 
 
+def worker_mesh(workers: int):
+    """A ``workers``-device mesh over the devices this process holds.  A
+    chip belongs to one process, so a benchmark never starts a JAX child
+    for more devices: too few is an error (on the CPU backend, start with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=<workers>``)."""
+    from repro.launch.mesh import make_host_mesh
+    have = len(jax.devices())
+    if have < workers:
+        raise RuntimeError(
+            f"needs {workers} devices, this process sees {have}")
+    return make_host_mesh(workers)
+
+
 def write_json(name: str, payload) -> Path:
     """Persist a benchmark payload as ``BENCH_<name>.json`` at the repo
     root (round-trippable: numpy/jax scalars and arrays are plain lists).

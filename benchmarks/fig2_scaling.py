@@ -1,78 +1,63 @@
 """Paper Figure 2 analogue: scaling of Asynchronous Randomized Gauss-Seidel
 with worker count (10 sweeps wall time, speedup vs 1 worker), against CG.
 
-Worker counts require separate processes (the XLA host-device count is fixed
-at first init), so each point runs in a subprocess with
---xla_force_host_platform_device_count=<P>.  On this container the devices
-share one physical core, so *wall-clock* speedups are not observable — we
-report the per-worker iteration counts and the communication rounds (the
-quantities that scale), plus wall time for completeness."""
+All worker counts run in this process, each on a mesh over its first P
+devices (a chip belongs to one process, so no point starts a child); a
+count above the devices this process holds fails.  On the CPU backend
+start with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``, where
+the devices share the host's cores, so *wall-clock* speedups are not
+observable — we report the per-worker iteration counts and the
+communication rounds (the quantities that scale), plus wall time for
+completeness."""
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
+import time
 
-from benchmarks.common import emit
+import jax
+import jax.numpy as jnp
 
-_WORKER_SCRIPT = textwrap.dedent("""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(P)d"
-import jax, jax.numpy as jnp
+from benchmarks.common import emit, worker_mesh
 from repro.core import parallel_rgs_solve, random_sparse_spd, theory
-from repro.launch.mesh import make_host_mesh
 
-P = %(P)d; n = %(n)d; sweeps = %(sweeps)d
-prob = random_sparse_spd(n, row_nnz=16, offdiag=0.95, n_rhs=4, seed=0)
-mesh = make_host_mesh(P)
-local = n // P
-rho = float(theory.rho(prob.A))
-tau = (P - 1) * local
-beta = theory.beta_opt(rho, tau)
-x0 = jnp.zeros_like(prob.x_star)
-# warmup (compile)
-r = parallel_rgs_solve(prob.A, prob.b, x0, prob.x_star, key=jax.random.key(0),
-                       mesh=mesh, rounds=1, local_steps=local, beta=beta)
-jax.block_until_ready(r.x)
-t0 = time.time()
-r = parallel_rgs_solve(prob.A, prob.b, x0, prob.x_star, key=jax.random.key(0),
-                       mesh=mesh, rounds=sweeps, local_steps=local, beta=beta)
-jax.block_until_ready(r.x)
-dt = time.time() - t0
-resid = float(jnp.linalg.norm(r.resid[-1]) / jnp.linalg.norm(prob.b))
-print(json.dumps(dict(P=P, tau=tau, beta=beta, wall_s=dt, resid=resid,
-                      iters_per_worker=sweeps * local, sync_rounds=sweeps)))
-""")
+
+def _point(prob, P: int, sweeps: int) -> dict:
+    n = prob.A.shape[0]
+    mesh = worker_mesh(P)
+    local = n // P
+    rho = float(theory.rho(prob.A))
+    tau = (P - 1) * local
+    beta = theory.beta_opt(rho, tau)
+    x0 = jnp.zeros_like(prob.x_star)
+    kw = dict(key=jax.random.key(0), mesh=mesh, local_steps=local, beta=beta)
+    # warmup (compile)
+    jax.block_until_ready(parallel_rgs_solve(
+        prob.A, prob.b, x0, prob.x_star, rounds=1, **kw).x)
+    t0 = time.time()
+    r = parallel_rgs_solve(prob.A, prob.b, x0, prob.x_star, rounds=sweeps,
+                           **kw)
+    jax.block_until_ready(r.x)
+    dt = time.time() - t0
+    resid = float(jnp.linalg.norm(r.resid[-1]) / jnp.linalg.norm(prob.b))
+    return dict(P=P, tau=tau, beta=beta, wall_s=dt, resid=resid,
+                iters_per_worker=sweeps * local, sync_rounds=sweeps)
 
 
 def run(n: int = 1024, sweeps: int = 10, workers=(1, 2, 4, 8)):
+    prob = random_sparse_spd(n, row_nnz=16, offdiag=0.95, n_rhs=4, seed=0)
     results = []
     for P in workers:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = "src"
-        env.pop("JAX_PLATFORMS", None)
-        out = subprocess.run(
-            [sys.executable, "-c", _WORKER_SCRIPT % dict(P=P, n=n, sweeps=sweeps)],
-            env=env, capture_output=True, text=True, timeout=900,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-        if out.returncode != 0:
-            emit("fig2_scaling", P=P, error=out.stderr.strip()[-200:])
-            continue
-        rec = json.loads(out.stdout.strip().splitlines()[-1])
+        rec = _point(prob, P, sweeps)
         results.append(rec)
         emit("fig2_scaling", P=rec["P"], tau=rec["tau"],
              beta=f"{rec['beta']:.3f}", wall_s=f"{rec['wall_s']:.2f}",
              resid_10sweeps=f"{rec['resid']:.3e}",
              iters_per_worker=rec["iters_per_worker"],
              sync_rounds=rec["sync_rounds"])
-    if results:
-        base = results[0]
-        for rec in results:
-            emit("fig2_scaling_derived", P=rec["P"],
-                 work_speedup=f"{base['iters_per_worker']/rec['iters_per_worker']:.2f}",
-                 resid_ratio_vs_P1=f"{rec['resid']/max(base['resid'],1e-30):.2f}")
+    base = results[0]
+    for rec in results:
+        emit("fig2_scaling_derived", P=rec["P"],
+             work_speedup=f"{base['iters_per_worker']/rec['iters_per_worker']:.2f}",
+             resid_ratio_vs_P1=f"{rec['resid']/max(base['resid'],1e-30):.2f}")
     return results
 
 

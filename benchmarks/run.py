@@ -1,11 +1,13 @@
 """Benchmark entry point: ``PYTHONPATH=src python -m benchmarks.run``.
 
-One module per paper table/figure (+ the roofline aggregation).  Output is
-CSV-ish lines ``benchmark,key=value,...`` — EXPERIMENTS.md quotes them.
+One module per paper table/figure, all in this one process.  Output is
+CSV-ish lines ``benchmark,key=value,...``; the exit code is non-zero when
+any job failed.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 
@@ -33,6 +35,7 @@ def main():
         ("bench_kernels", lambda: bench_kernels.run(
             n=512 if args.fast else 1024)),
     ]
+    failed = []
     for name, fn in jobs:
         if args.only and args.only not in name:
             continue
@@ -40,10 +43,14 @@ def main():
         print(f"# === {name} ===", flush=True)
         try:
             fn()
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other jobs, then fail
+            failed.append(name)
             print(f"{name},error={type(e).__name__}:{e}", flush=True)
         print(f"# {name} done in {time.time()-t0:.1f}s", flush=True)
+    if failed:
+        print(f"# failed: {','.join(failed)}", flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

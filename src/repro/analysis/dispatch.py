@@ -12,10 +12,10 @@ table row; this checker makes that class of drift mechanical:
 * DX2 — a dispatch hole: an ``(action, format)`` pair where both the
   action and the format appear elsewhere in the table but the pair has
   no row under any sync (how the EllOp hole looked);
-* DX3 — a capability set with no fallback guard: no
-  ``if ... kind not in <SET>:`` whose body warns (``_warn_*`` helper or
+* DX3 — a capability set with no guard: no ``if ... kind not in <SET>:``
+  whose body raises or warns (``raise``, a ``_warn_*`` helper or
   ``warnings.warn``) — requests for the feature would be silently
-  ignored or crash instead of downgrading loudly;
+  ignored or crash instead of failing or downgrading loudly;
 * DX4 — a duplicated capability literal: a tuple/set/list of string
   constants somewhere in ``src/repro`` equal (as a set) to one of the
   named capability constants, instead of referencing the constant — the
@@ -113,6 +113,8 @@ def _has_fallback_guard(tree: ast.AST, set_name: str) -> bool:
         if not hit:
             continue
         for sub in ast.walk(node):
+            if isinstance(sub, ast.Raise):
+                return True
             if isinstance(sub, ast.Call):
                 cn = call_name(sub) or ""
                 if cn == "warnings.warn" or cn.split(".")[-1].startswith("_warn_"):
@@ -181,9 +183,9 @@ def check_repo(root: str, parsed: dict[str, tuple[ast.AST, str]]
             findings.append(Finding(
                 code="DX3", path=table_path, line=constants[set_name][1],
                 symbol=set_name,
-                message=(f"no `kind not in {set_name}` fallback guard that "
-                         "warns — feature requests outside the set would be "
-                         "silently ignored or crash")))
+                message=(f"no `kind not in {set_name}` guard that raises "
+                         "or warns — feature requests outside the set would "
+                         "be silently ignored or crash")))
 
     # DX5 — dispatch-miss error must enumerate the table programmatically
     for fn in _get_functions(table_tree):

@@ -1,73 +1,28 @@
-"""jax version-compatibility shims.
+"""The jax sharding API as the solvers call it (jax 0.9).
 
-The solver and model code targets the modern jax API: ``jax.shard_map`` at
-the top level, the varying-manual-axes type system (``jax.lax.pvary``), and
-mesh axis types (``jax.sharding.AxisType``).  Older jax releases (0.4.x,
-which some CPU-only CI images pin) ship ``shard_map`` under
-``jax.experimental``, spell the replication-check kwarg ``check_rep``, and
-have neither ``pvary`` nor ``AxisType``.  Every call site imports from this
-module so exactly one place owns the fallbacks.
+One home for the three spellings the distributed engine, the mesh helpers
+and the legacy-solver oracle share, so a jax API change lands here once.
 """
 from __future__ import annotations
 
 import jax
 
-try:  # modern jax: top-level shard_map with the VMA type system
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax < 0.6: experimental home, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the replication/VMA check spelled portably.
-
-    Defaults to ``check_vma=False`` (legacy semantics): the solvers return
-    post-all-gather replicas whose bitwise equality across workers the type
-    system cannot prove, and old-jax ``check_rep`` rejects exactly those.
-    """
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: check_vma},
-    )
+    """``jax.shard_map`` with the VMA check off by default: the solvers
+    return post-all-gather replicas whose bitwise equality across workers
+    the type system cannot prove."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def pvary(x, axis_names):
-    """Mark ``x`` device-varying along ``axis_names`` (no-op on old jax)."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axis_names) if fn is not None else x
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every jax version.
-
-    Old jax returns a one-element list of per-program dicts; modern jax
-    returns the dict directly.
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """Mark ``x`` device-varying along ``axis_names``."""
+    return jax.lax.pcast(x, axis_names, to="varying")
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types when the install has them.
-
-    Falls back through: axis-typed make_mesh (modern) -> plain make_mesh
-    (>= 0.4.35) -> mesh_utils.create_device_mesh + Mesh (older 0.4.x,
-    where jax.make_mesh does not exist yet).
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(axis_type.Auto,) * len(axis_names))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names)
-    from jax.experimental import mesh_utils
-
-    devices = mesh_utils.create_device_mesh(axis_shapes)
-    return jax.sharding.Mesh(devices, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -183,9 +183,9 @@ class Schedule(NamedTuple):
     mode, the local phase of a distributed round) as a single fused Pallas
     sweep kernel — the iterate VMEM-resident across all steps, the pick
     stream scalar-prefetched — instead of a per-step ``lax.scan``.
-    Action × format combinations without a sweep kernel fall back to the
-    scan engine with a ``UserWarning``; supported combinations produce
-    iterates matching the scan engine (GS bitwise, RK to roundoff).
+    An explicit ``fused=True`` on an action × format combination without a
+    sweep kernel is an error; supported combinations produce iterates
+    matching the scan engine to f32 roundoff.
     ``fused="auto"`` defers the pick to the active tuning table
     (``repro.tune``): the measured fused-vs-scan winner for this
     format × action × shape bucket on the current backend runs; with no
@@ -311,19 +311,24 @@ def record_metrics(op, b, x, x_star, *, norm: str):
     ``x_star=None`` (a real workload: nobody knows the solution) yields
     NaN ``err_sq`` and the finite residual — the same convention the
     distributed strategies adopted in the PR-6 crash sweep.
+
+    Matmuls here run at f32 precision on every backend: at the TPU's
+    default (bf16 operand passes) the reported residual would carry
+    ~1e-3 ||b|| of rounding, more than a converged solve's residual.
     """
     if norm not in ("A", "euclid"):
         raise ValueError(norm)
     mv = getattr(op, "matvec_ref", op.matvec)
-    if x_star is None:
-        err = jnp.full((x.shape[1],), jnp.nan, jnp.float32)
-    else:
-        e = x - x_star
-        if norm == "A":
-            err = jnp.einsum("nk,nk->k", e, mv(e))
+    with jax.default_matmul_precision("highest"):
+        if x_star is None:
+            err = jnp.full((x.shape[1],), jnp.nan, jnp.float32)
         else:
-            err = jnp.einsum("nk,nk->k", e, e)
-    return err, jnp.linalg.norm(b - mv(x), axis=0)
+            e = x - x_star
+            if norm == "A":
+                err = jnp.einsum("nk,nk->k", e, mv(e))
+            else:
+                err = jnp.einsum("nk,nk->k", e, e)
+        return err, jnp.linalg.norm(b - mv(x), axis=0)
 
 
 def resolve_record_every(num_iters: int, record_every: int) -> int:
@@ -391,8 +396,8 @@ def _fused_sweep_supported(op, action: str, block: int) -> bool:
 
     The sweep layer covers the banded block-GS action (kernels/banded_gs),
     and the padded-row coordinate-GS / Kaczmarz actions for CsrOp / EllOp
-    (kernels/sweep_csr, kernels/sweep_ell).  Dense formats and block > 1
-    row-panel GS stay on the scan engine.
+    (kernels/sweep_csr).  Dense formats and block > 1 row-panel GS stay
+    on the scan engine.
     """
     if action == "gs":
         if isinstance(op, BlockBandedOp):
@@ -403,11 +408,19 @@ def _fused_sweep_supported(op, action: str, block: int) -> bool:
     return False
 
 
-def _warn_fused_fallback(op, action, detail=""):
-    warnings.warn(
+def _no_fused_kernel(op, action, detail=""):
+    """The error for an explicit ``fused=True`` that no kernel can honor
+    (``fused="auto"`` resolves to the scan engine instead)."""
+    return NotImplementedError(
         f"fused=True: no fused sweep kernel for action={action!r} x "
-        f"{type(op).__name__}{detail}; falling back to the per-step scan "
-        "engine", UserWarning, stacklevel=3)
+        f"{type(op).__name__}{detail}; pass fused=False for the per-step "
+        "scan engine, or fused='auto' to let the tuning table pick")
+
+
+def _require_fused_kernel(op, action: str, block: int) -> None:
+    if not _fused_sweep_supported(op, action, block):
+        raise _no_fused_kernel(
+            op, action, f" with block={block}" if block != 1 else "")
 
 
 def _warn_overlap_fallback(op, action, kind):
@@ -450,8 +463,9 @@ def solve_sequential(
     (the operator's ``gs_sweep``/``rk_sweep`` entry point: iterate
     VMEM-resident, picks scalar-prefetched) instead of a per-step
     ``lax.scan``; the pick stream and update arithmetic are shared, so
-    iterates match the scan engine (GS bitwise, RK to roundoff).  Formats
-    without a sweep kernel fall back to the scan with a ``UserWarning``.
+    iterates match the scan engine to f32 roundoff (the kernels sum a
+    row's inner product in slot order).  Formats without a sweep kernel
+    raise ``NotImplementedError``.
     ``fused="auto"`` runs the tuning table's measured winner where a
     sweep kernel exists, the scan otherwise — silently, since nothing
     was forced (see ``Schedule``).
@@ -460,13 +474,11 @@ def solve_sequential(
         fused = (_fused_sweep_supported(op, action, block)
                  and tune_runtime.resolve_fused(fused, op, action))
     if fused:
-        if _fused_sweep_supported(op, action, block):
-            return _sequential_fused_impl(
-                op, b, x0, x_star, action=action, key=key,
-                num_iters=num_iters, beta=float(beta), block=block,
-                record_every=record_every)
-        _warn_fused_fallback(
-            op, action, f" with block={block}" if block != 1 else "")
+        _require_fused_kernel(op, action, block)
+        return _sequential_fused_impl(
+            op, b, x0, x_star, action=action, key=key,
+            num_iters=num_iters, beta=float(beta), block=block,
+            record_every=record_every)
     return _sequential_scan_impl(
         op, b, x0, x_star, action=action, key=key, num_iters=num_iters,
         beta=beta, block=block, record_every=record_every)
@@ -809,10 +821,10 @@ def solve_distributed(
     Cimmino-within-panel action over VMEM-resident window + delta
     carries) — and on the sparse strategies: sparse slab GS
     (``sweep_rows_gs`` with the slab's traced write base scalar-
-    prefetched; bitwise-identical iterates) and sparse local-sampling RK
-    (``sweep_rows_rk_delta``, the two-carry replica+delta sweep, iterates
-    to roundoff).  Strategies without a fused local phase fall back to
-    the per-step scan with a ``UserWarning``.
+    prefetched) and sparse local-sampling RK (``sweep_rows_rk_delta``, the
+    two-carry replica+delta sweep); the sparse phases match the scan to
+    f32 roundoff.  An explicit ``fused=True`` on a strategy without a
+    fused local phase raises ``NotImplementedError``.
 
     ``overlap=True`` double-buffers the synchronization: round r's
     exchange payload (halo edges / slab rotations / round delta) is the
@@ -913,8 +925,7 @@ def solve_distributed(
         fused = (kind in _FUSED_STRATEGIES
                  and tune_runtime.resolve_fused(fused, op, action))
     if fused and kind not in _FUSED_STRATEGIES:
-        _warn_fused_fallback(op, action, f" under the {kind!r} strategy")
-        fused = False
+        raise _no_fused_kernel(op, action, f" under the {kind!r} strategy")
     if overlap and kind not in _OVERLAP_STRATEGIES:
         _warn_overlap_fallback(op, action, kind)
         overlap = False
@@ -1745,7 +1756,8 @@ def _sparse_gs(op, b, x0, xs, key, *, mesh, axis, rounds, local_steps, beta,
     per round: the replica stays VMEM-resident across all ``local_steps``
     updates and the slab offset rides the scalar-prefetch channel as the
     kernel's write base (it is traced — ``axis_index`` under shard_map).
-    The arithmetic is the scan step's, so iterates are bitwise identical.
+    The kernel sums each row's inner product in slot order, so iterates
+    match the scan step to f32 roundoff.
 
     ``overlap=True`` exchanges the own slab captured at the END of round
     r-1 (carried through the round scan) while round r's sweep runs on
@@ -2163,9 +2175,11 @@ def sequential_chunk(op, b, x, picks, *, action: str, beta: float = 1.0,
     ``solve_sequential``.
     """
     if fused == "auto":
-        fused = tune_runtime.resolve_fused(fused, op, action)
+        fused = (_fused_sweep_supported(op, action, block)
+                 and tune_runtime.resolve_fused(fused, op, action))
     impl = _sequential_scan_impl
-    if fused and _fused_sweep_supported(op, action, block):
+    if fused:
+        _require_fused_kernel(op, action, block)
         impl = _sequential_fused_impl
     res = impl(op, b, x, None, action=action, key=jax.random.key(0),
                num_iters=picks.shape[0], beta=beta, block=block,
@@ -2289,8 +2303,8 @@ def solve(
     ``b`` and all accumulation stay f32 regardless), and ``gs_block`` the
     dense/CSR block-GS action granularity.  ``fused`` overrides
     ``schedule.fused`` (``None`` defers to the schedule): run inner loops
-    as fused Pallas sweep kernels where the action × format has one,
-    falling back to the per-step scan with a warning elsewhere;
+    as fused Pallas sweep kernels (an error where the action × format has
+    none);
     ``"auto"`` runs the tuning table's measured fused-vs-scan winner
     (see ``Schedule``).
     """

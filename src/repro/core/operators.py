@@ -353,8 +353,15 @@ class EllOp:
         return None
 
     def matvec(self, x: jax.Array, *, interpret=None) -> jax.Array:
+        """``A @ x`` via the ELL kernel; the row count pads to whole
+        128-row tiles with zero-valued rows, whose outputs are dropped."""
         from repro.kernels import ops
-        return ops.spmv_ell(self.vals, self.cols, x, interpret=interpret)
+        n = self.vals.shape[0]
+        pad = -n % 128
+        vals = jnp.pad(self.vals, ((0, pad), (0, 0)))
+        cols = jnp.pad(self.cols, ((0, pad), (0, 0)))
+        return ops.spmv_ell(vals, cols, x, tile=128,
+                            interpret=interpret)[:n]
 
     def matvec_ref(self, x: jax.Array) -> jax.Array:
         from repro.kernels import ref
@@ -389,21 +396,22 @@ class EllOp:
 
     def gs_sweep(self, b, x, picks, *, beta: float = 1.0, write_base=0,
                  interpret=None) -> jax.Array:
-        """Fused sequential coordinate-GS sweep (kernels/sweep_ell.py).
+        """Fused sequential coordinate-GS sweep (kernels/sweep_csr.py —
+        ELL storage already is the padded-row form it streams).
         ``write_base`` offsets writes for distributed slab-local phases."""
         from repro.kernels import ops
-        return ops.sweep_ell_gs(self.vals, self.cols, b, x, picks,
-                                beta=beta, write_base=write_base,
-                                interpret=interpret)
+        return ops.sweep_rows_gs(self.vals, self.cols, b, x, picks,
+                                 beta=beta, write_base=write_base,
+                                 interpret=interpret)
 
     def rk_sweep(self, b, rn, x, picks, *, beta: float = 1.0,
                  interpret=None) -> jax.Array:
-        """Fused sequential Kaczmarz sweep (kernels/sweep_ell.py).  ``rn``
+        """Fused sequential Kaczmarz sweep (kernels/sweep_csr.py).  ``rn``
         is the caller's row-norm vector so the divisor matches the scan
         engine's sampling distribution exactly."""
         from repro.kernels import ops
-        return ops.sweep_ell_rk(self.vals, self.cols, b, rn, x, picks,
-                                beta=beta, interpret=interpret)
+        return ops.sweep_rows_rk(self.vals, self.cols, b, rn, x, picks,
+                                 beta=beta, interpret=interpret)
 
     def slab_neighbors(self, num_workers: int) -> np.ndarray:
         """Row-slab neighbor graph (host-side; see slab_neighbor_matrix).
@@ -748,7 +756,8 @@ class CsrOp:
         """Fused sequential coordinate-GS sweep (kernels/sweep_csr.py):
         the row windows stream via scalar-prefetch index maps over the
         ``padded_rows()`` form — the same masked windows ``row_dot``
-        reads, so the iterate is bitwise the scan engine's.
+        reads, summed in slot order, so the iterate matches the scan
+        engine's to f32 roundoff.
         ``write_base`` offsets writes for distributed slab-local phases."""
         from repro.kernels import ops
         vals, cols = self.padded_rows()

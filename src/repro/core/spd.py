@@ -44,13 +44,31 @@ def to_unit_diagonal(B: jax.Array) -> tuple[jax.Array, jax.Array]:
     return A, d
 
 
+#: Largest order whose eigenvalue extremes come from a dense ``eigvalsh``.
+#: Above it (the n = 16384 reference scale) a dense decomposition costs
+#: minutes — on a TPU its compile alone outgrows a 40 GiB host — so the
+#: extremes come from float64 Lanczos on a sparse host copy instead.
+DENSE_EIG_MAX = 4096
+
+
+def _extreme_eigs(A: jax.Array) -> tuple[jax.Array, jax.Array]:
+    if A.shape[0] <= DENSE_EIG_MAX:
+        evals = jnp.linalg.eigvalsh(A)
+        return evals[0], evals[-1]
+    import scipy.sparse
+    from scipy.sparse.linalg import eigsh
+    S = scipy.sparse.csr_matrix(np.asarray(A, np.float64))
+    lo, hi = eigsh(S, k=2, which="BE", return_eigenvectors=False)
+    return jnp.asarray(lo, A.dtype), jnp.asarray(hi, A.dtype)
+
+
 def _finish(A: np.ndarray, key: jax.Array, n_rhs: int) -> SPDProblem:
     A = jnp.asarray(A, jnp.float64 if jax.config.jax_enable_x64 else jnp.float32)
     A, _ = to_unit_diagonal(A)
-    evals = jnp.linalg.eigvalsh(A)
+    lam_min, lam_max = _extreme_eigs(A)
     x_star = jax.random.normal(key, (A.shape[0], n_rhs), A.dtype)
     b = A @ x_star
-    return SPDProblem(A=A, b=b, x_star=x_star, lam_min=evals[0], lam_max=evals[-1])
+    return SPDProblem(A=A, b=b, x_star=x_star, lam_min=lam_min, lam_max=lam_max)
 
 
 def random_sparse_spd(

@@ -123,7 +123,7 @@ def _rk_kernel(idx_ref, gate_ref, a_ref, b_ref, rn_ref, x_ref, d_ref,
         acc -= jnp.dot(a_ref[0, d], xs, preferred_element_type=jnp.float32)
     g = acc.astype(xo_ref.dtype)
     betam = jnp.where(gate_ref[s] > 0, beta, 0.0)
-    gn = (betam * g / rn_ref[0][:, None]).astype(jnp.float32)
+    gn = (betam * g / rn_ref[...]).astype(jnp.float32)  # rn_ref: (block, 1)
     for d in range(width):
         contrib = jnp.dot(a_ref[0, d].T, gn,
                           preferred_element_type=jnp.float32)
@@ -181,7 +181,10 @@ def banded_rk_sweep(
             pl.BlockSpec((1, width, block, block),
                          lambda s, idx, gate: (idx[s], 0, 0, 0)),
             pl.BlockSpec((block, k), lambda s, idx, gate: (idx[s], 0)),
-            pl.BlockSpec((1, block), lambda s, idx, gate: (idx[s], 0)),
+            # (nb_local, block, 1) view: the block's last two dims are the
+            # array's own, and the column broadcasts over the k lanes.
+            pl.BlockSpec((pl.Squeezed(), block, 1),
+                         lambda s, idx, gate: (idx[s], 0, 0)),
             pl.BlockSpec(xw.shape, lambda s, idx, gate: (0, 0)),
             pl.BlockSpec(dw.shape, lambda s, idx, gate: (0, 0)),
         ],
@@ -194,8 +197,8 @@ def banded_rk_sweep(
         out_shape=(jax.ShapeDtypeStruct(xw.shape, xw.dtype),
                    jax.ShapeDtypeStruct(dw.shape, dw.dtype)),
         interpret=interpret,
-    )(picks.astype(jnp.int32), gates.astype(jnp.int32), A_bands, b, rn, xw,
-      dw)
+    )(picks.astype(jnp.int32), gates.astype(jnp.int32), A_bands, b,
+      rn.reshape(nb_local, block, 1), xw, dw)
 
 
 def pack_bands_local(A_bands_global: jax.Array, lo_block: int, nb_local: int,

@@ -1,15 +1,15 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute in interpret mode; on TPU they
-compile natively.  ``interpret=None`` auto-detects.  All wrappers fall back
-to the pure-jnp reference implementation when shapes violate kernel tiling
-constraints, so callers can use them unconditionally.
+On the CPU backend kernels execute in interpret mode; on TPU they compile
+natively.  ``interpret=None`` auto-detects.  No wrapper substitutes the
+pure-jnp reference for its kernel: a shape that breaks a kernel's tiling
+is an error, and a caller that wants the reference calls
+``repro.kernels.ref`` itself.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.kernels import ref
 from repro.kernels.banded_gs import (banded_gs_sweep as _banded_gs_sweep,
                                      banded_rk_sweep as _banded_rk_sweep)
 from repro.kernels.bbmv import bbmv as _bbmv, dense_to_bands
@@ -25,11 +25,6 @@ from repro.kernels.sweep_csr import (
     sweep_rows_gs as _sweep_rows_gs,
     sweep_rows_rk as _sweep_rows_rk,
     sweep_rows_rk_delta as _sweep_rows_rk_delta,
-)
-from repro.kernels.sweep_ell import (
-    sweep_ell_gs as _sweep_ell_gs,
-    sweep_ell_rk as _sweep_ell_rk,
-    sweep_ell_rk_delta as _sweep_ell_rk_delta,
 )
 
 
@@ -47,9 +42,13 @@ def _interp(interpret):
     return interpret
 
 
+def _require_tiles(n, tile, what):
+    if n % tile != 0:
+        raise ValueError(f"{what}: {n} rows do not tile by {tile}")
+
+
 def block_gs_sweep(A, b, x, blocks, *, block=128, beta=1.0, interpret=None):
-    if A.shape[0] % block != 0:
-        return ref.block_gs_sweep_ref(A, b, x, blocks, block=block, beta=beta)
+    _require_tiles(A.shape[0], block, "block_gs_sweep")
     return _block_gs_sweep(
         A, b, x, blocks, block=block, beta=beta, interpret=_interp(interpret)
     )
@@ -60,8 +59,7 @@ def bbmv(A_bands, x, *, bands, block, interpret=None):
 
 
 def spmv_ell(vals, cols, x, *, tile=128, interpret=None):
-    if vals.shape[0] % tile != 0:
-        return ref.spmv_ell_ref(vals, cols, x)
+    _require_tiles(vals.shape[0], tile, "spmv_ell")
     return _spmv_ell(vals, cols, x, tile=tile, interpret=_interp(interpret))
 
 
@@ -141,26 +139,6 @@ def sweep_rows_rk_delta(vals, cols, b, rn, x, d, picks, *, beta=1.0,
                                 interpret=_interp(interpret))
 
 
-def sweep_ell_gs(vals, cols, b, x, picks, *, beta=1.0, write_base=0,
-                 interpret=None):
-    """Fused coordinate-GS sweep on ELL storage (kernels/sweep_ell.py)."""
-    return _sweep_ell_gs(vals, cols, b, x, picks, beta=beta,
-                         write_base=write_base, interpret=_interp(interpret))
-
-
-def sweep_ell_rk(vals, cols, b, rn, x, picks, *, beta=1.0, interpret=None):
-    """Fused Kaczmarz sweep on ELL storage (kernels/sweep_ell.py)."""
-    return _sweep_ell_rk(vals, cols, b, rn, x, picks, beta=beta,
-                         interpret=_interp(interpret))
-
-
-def sweep_ell_rk_delta(vals, cols, b, rn, x, d, picks, *, beta=1.0,
-                       interpret=None):
-    """Fused two-carry Kaczmarz sweep on ELL storage."""
-    return _sweep_ell_rk_delta(vals, cols, b, rn, x, d, picks, beta=beta,
-                               interpret=_interp(interpret))
-
-
 __all__ = [
     "banded_gs_sweep",
     "banded_rk_sweep",
@@ -172,9 +150,6 @@ __all__ = [
     "spmv_csr_sliced",
     "spmv_csr_sliced_prefetch",
     "spmv_ell",
-    "sweep_ell_gs",
-    "sweep_ell_rk",
-    "sweep_ell_rk_delta",
     "sweep_rows_gs",
     "sweep_rows_rk",
     "sweep_rows_rk_delta",

@@ -28,6 +28,7 @@ from repro.core import (Schedule, cg_solve, random_lsq, random_sparse_lsq,
 from repro.core.engine import (COMPRESS_MODES, PARTITIONS, scheduled_tau,
                                supported_syncs)
 from repro.core.operators import STORAGE_DTYPES
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.solve import add_fused_flag
 
@@ -92,6 +93,7 @@ def main(argv=None):
                     help="updates between synchronizations (0 -> m/workers)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.format != "csr":
         if args.rk_sync == "a2a":
             ap.error("--rk-sync a2a needs --format csr")

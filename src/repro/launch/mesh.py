@@ -1,23 +1,14 @@
-"""Production mesh construction.
+"""Worker-mesh construction for the distributed solvers.
 
-A FUNCTION, not a module-level constant: importing this module never touches
-jax device state (the dry-run must set XLA_FLAGS before the first jax call).
-
-Single pod: (16, 16) = 256 v5e chips, axes ("data", "model").
-Two pods:   (2, 16, 16), axes ("pod", "data", "model") — the pod axis is
-outer data parallelism over DCN.
+A FUNCTION, not a module-level constant: importing this module never
+touches jax device state (a forced-host-device run must set XLA_FLAGS
+before the first jax call).
 """
 from __future__ import annotations
 
 import jax
 
 from repro.compat import make_mesh as _mesh
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
 
 
 def make_host_mesh(workers: int | None = None, axis: str = "workers"):

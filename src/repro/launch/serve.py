@@ -14,6 +14,7 @@ from __future__ import annotations
 import argparse
 
 from repro.core import random_sparse_lsq, random_sparse_spd
+from repro.launch.cache import enable_compile_cache
 from repro.launch.solve import FORMAT_CHOICES, add_fused_flag
 from repro.serve import SolverService, open_loop_load
 
@@ -61,6 +62,7 @@ def main(argv=None):
     ap.add_argument("--no-warmup", dest="warmup", action="store_false")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     svc, name = build_service(args)
     with svc:

@@ -21,6 +21,7 @@ from repro.core import (Schedule, cg_solve, random_sparse_spd, solve, theory)
 from repro.core.engine import (COMPRESS_MODES, PARTITIONS, scheduled_tau,
                                supported_syncs)
 from repro.core.operators import STORAGE_DTYPES
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 #: operator class names this CLI can build (--format dense/ell/csr); the
@@ -35,7 +36,7 @@ FORMAT_CHOICES = ("dense", "ell", "csr")
 def add_fused_flag(ap: argparse.ArgumentParser, detail: str) -> None:
     """The tri-state ``--fused`` flag every launcher shares: absent ->
     False (the scan engine, today's default), bare ``--fused`` -> True
-    (forced, warns where no fused kernel exists), ``--fused auto`` ->
+    (forced, an error where no fused kernel exists), ``--fused auto`` ->
     the tuning table's measured fused-vs-scan winner per strategy row
     (``repro.tune``; missing entries run the scan, silently)."""
 
@@ -48,10 +49,10 @@ def add_fused_flag(ap: argparse.ArgumentParser, detail: str) -> None:
     ap.add_argument("--fused", nargs="?", const=True, default=False,
                     type=value, metavar="auto",
                     help="run inner loops as fused Pallas sweep kernels "
-                         f"({detail}); bare --fused forces it (falls back "
-                         "to the per-step scan with a warning where no "
-                         "sweep kernel exists), '--fused auto' runs the "
-                         "tuning table's measured winner per strategy row")
+                         f"({detail}); bare --fused forces it (an error "
+                         "where no sweep kernel exists), '--fused auto' "
+                         "runs the tuning table's measured winner per "
+                         "strategy row")
 
 
 def main(argv=None):
@@ -106,6 +107,7 @@ def main(argv=None):
                          "(0 -> one sweep split evenly)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.sync == "a2a" and args.format == "dense":
         ap.error("--sync a2a needs a sparse format (--format csr or ell)")
     if args.partition == "balanced" and args.format == "dense":

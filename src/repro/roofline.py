@@ -1,165 +1,9 @@
-"""Roofline extraction from compiled dry-run artifacts.
-
-Three terms per (arch x shape x mesh), all in seconds (EXPERIMENTS.md §Roofline):
-
-    T_comp = HLO_FLOPs_per_device / peak_FLOPs
-    T_mem  = HLO_bytes_per_device / HBM_bw
-    T_coll = sum over collectives of wire_bytes_per_device / ICI_bw
-
-FLOPs/bytes come from ``compiled.cost_analysis()`` on the SPMD-partitioned
-module (already per-device).  Collective bytes are NOT in cost_analysis —
-we parse ``compiled.as_text()`` and model per-device wire traffic of ring
-algorithms (g = replica-group size, O = per-device buffer bytes):
-
-    all-gather          O * (g-1)         (O = output bytes / g)
-    reduce-scatter      O * (g-1)         (O = output bytes)
-    all-reduce          2 * O * (g-1)/g   (reduce-scatter + all-gather)
-    all-to-all          O * (g-1)/g
-    collective-permute  O
-
-Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
-The roofline table is single-pod (all collectives ride ICI); the multi-pod
-dry-run only proves the pod axis shards (DESIGN.md §6).
+"""Published peaks of one TPU v5e chip (Google Cloud documentation, "TPU
+v5e"), the denominators of the roofline terms ``benchmarks/bench_kernels.py``
+reports.  A roofline share is only meaningful over a kernel time measured
+on that chip.
 """
 from __future__ import annotations
 
-import re
-from dataclasses import dataclass, field
-
 PEAK_FLOPS = 197e12      # bf16 / chip
 HBM_BW = 819e9           # bytes/s / chip
-ICI_BW = 50e9            # bytes/s / link
-
-_DTYPE_BYTES = {
-    "pred": 1, "s2": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
-    "s32": 4, "u32": 4, "s64": 8, "u64": 8,
-    "f8e4m3fn": 1, "f8e5m2": 1, "bf16": 2, "f16": 2, "f32": 4, "f64": 8,
-    "c64": 8, "c128": 16,
-}
-
-_SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
-_COLL_RE = re.compile(
-    r"=\s+((?:\([^)]*\))|(?:[a-z0-9]+\[[0-9,]*\][^ ]*))\s+"
-    r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start|-done)?\(")
-_GROUPS_RE = re.compile(r"replica_groups=\{\{([0-9,]+)\}")
-_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
-
-
-def shape_bytes(type_str: str) -> int:
-    """Bytes of an HLO type string; tuples sum their components."""
-    total = 0
-    for dt, dims in _SHAPE_RE.findall(type_str):
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-def _group_size(line: str) -> int:
-    m = _GROUPS_RE.search(line)
-    if m:
-        return len(m.group(1).split(","))
-    m = _GROUPS_IOTA_RE.search(line)   # replica_groups=[n_groups,group_size]
-    if m:
-        return int(m.group(2))
-    return 1
-
-
-@dataclass
-class CollectiveStats:
-    wire_bytes: float = 0.0                       # per device, ring model
-    by_kind: dict = field(default_factory=dict)   # kind -> (count, wire_bytes)
-
-    def add(self, kind: str, wire: float):
-        self.wire_bytes += wire
-        c, b = self.by_kind.get(kind, (0, 0.0))
-        self.by_kind[kind] = (c + 1, b + wire)
-
-
-def collective_bytes(hlo_text: str) -> CollectiveStats:
-    """Sum modeled per-device wire bytes over every collective op."""
-    stats = CollectiveStats()
-    seen_done = set()
-    for line in hlo_text.splitlines():
-        m = _COLL_RE.search(line)
-        if not m:
-            continue
-        if "-done(" in line:
-            continue   # -start carries the shapes; -done would double count
-        type_str, kind = m.group(1), m.group(2)
-        out_bytes = shape_bytes(type_str)
-        g = _group_size(line)
-        if g <= 1:
-            if kind != "collective-permute":
-                continue
-        if kind == "all-gather":
-            wire = out_bytes * (g - 1) / max(g, 1)
-        elif kind == "reduce-scatter":
-            wire = out_bytes * (g - 1)
-        elif kind == "all-reduce":
-            wire = 2.0 * out_bytes * (g - 1) / max(g, 1)
-        elif kind == "all-to-all":
-            wire = out_bytes * (g - 1) / max(g, 1)
-        else:  # collective-permute
-            wire = out_bytes
-        stats.add(kind, wire)
-    return stats
-
-
-@dataclass
-class Roofline:
-    flops: float              # per device
-    mem_bytes: float          # per device
-    coll: CollectiveStats
-    t_comp: float
-    t_mem: float
-    t_coll: float
-    bottleneck: str
-    model_flops: float = 0.0  # 6*N*D (useful) across all devices
-    chips: int = 1
-
-    @property
-    def t_max(self):
-        return max(self.t_comp, self.t_mem, self.t_coll)
-
-    @property
-    def useful_ratio(self):
-        total = self.flops * self.chips
-        return self.model_flops / total if total else 0.0
-
-    @property
-    def roofline_fraction(self):
-        """How close the dominant term says we are to the hardware roof for
-        the *useful* work: useful_time_at_peak / modeled_step_time."""
-        if self.t_max <= 0:
-            return 0.0
-        useful_t = self.model_flops / (self.chips * PEAK_FLOPS)
-        return useful_t / self.t_max
-
-
-def analyze(cost: dict, hlo_text: str, *, chips: int, model_flops: float = 0.0
-            ) -> Roofline:
-    flops = float(cost.get("flops", 0.0))
-    mem = float(cost.get("bytes accessed", 0.0))
-    coll = collective_bytes(hlo_text)
-    t_comp = flops / PEAK_FLOPS
-    t_mem = mem / HBM_BW
-    t_coll = coll.wire_bytes / ICI_BW
-    terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
-    bottleneck = max(terms, key=terms.get)
-    return Roofline(flops=flops, mem_bytes=mem, coll=coll, t_comp=t_comp,
-                    t_mem=t_mem, t_coll=t_coll, bottleneck=bottleneck,
-                    model_flops=model_flops, chips=chips)
-
-
-def model_flops_train(n_active_params: int, tokens: int) -> float:
-    return 6.0 * n_active_params * tokens
-
-
-def model_flops_decode(n_active_params: int, batch: int) -> float:
-    return 2.0 * n_active_params * batch

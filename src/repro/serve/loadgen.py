@@ -31,6 +31,8 @@ class LoadReport(NamedTuple):
     converged: int            # requests with every column converged
     rounds_per_request: list  # max record chunks any of a request's columns took
     latencies_ms: list
+    rhs: list                 # each request's (n, c) RHS, in submission order
+    results: list             # each request's RequestResult, same order
 
 
 def percentile(sorted_vals, q: float) -> float:
@@ -80,4 +82,6 @@ def open_loop_load(service, problem: str, *, requests: int, rate_hz: float,
         rounds_per_request=[int(np.asarray(r.rounds).max())
                             for r in results],
         latencies_ms=lat,
+        rhs=[b for b, _ in plan],
+        results=results,
     )

@@ -12,7 +12,8 @@ hardcoding a pick.  Resolution order, everywhere:
 3. no entry (or no table) -> today's hardcoded default, bitwise-unchanged.
 
 The active table defaults to the committed ``TUNE_<backend>.json`` for
-the current backend (lazily loaded once; missing file -> no table).  The
+the current backend (lazily loaded once; missing file -> no table; a file
+that fails to load raises).  The
 ``REPRO_TUNE_TABLE`` environment variable overrides: a path loads that
 file, ``off``/``none``/``0`` disables lookups entirely.  Tests and the
 autotuner swap tables with ``use_table`` / ``set_active_table``.
@@ -45,7 +46,9 @@ def _load_default() -> TuningTable | None:
             path = env or default_path()
             try:
                 _default_cache = TuningTable.load(path)
-            except (OSError, ValueError):
+            except FileNotFoundError:
+                # no table for this backend: every lookup defaults; a
+                # table that exists but does not load is an error
                 _default_cache = None
     return _default_cache
 

@@ -3,8 +3,8 @@ import subprocess
 import sys
 import textwrap
 
-# Tests run against the single real CPU device (the dry-run, and ONLY the
-# dry-run, forces 512 placeholder devices — in its own process).
+# Tests run against the single real CPU device; multi-device tests force
+# host-platform devices in a child process of their own.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax  # noqa: E402
@@ -33,9 +33,8 @@ def run_in_subprocess(argv, *, timeout=600):
     """Run ``argv`` in a fresh interpreter from the repo root.
 
     The subprocess gets PYTHONPATH=src and is pinned to the CPU backend:
-    the forced host-platform placeholder devices these tests rely on are
-    CPU devices, and letting jax probe a (libtpu-equipped but TPU-less)
-    image first can hang for minutes on multi-host discovery.
+    the forced host-platform devices these tests rely on are CPU devices,
+    and a child must never reach for an accelerator its parent may hold.
     """
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"

@@ -138,7 +138,7 @@ OVERLAP_SCRIPT = textwrap.dedent("""
         # measured staleness respects the scheduled overlap bound
         assert int(lag.max()) + base_tau <= int(r.tau)
 
-    # --- sparse_gs: overlap converges, lag as scheduled, fused bitwise ---
+    # --- sparse_gs: overlap converges, lag as scheduled, fused matches ---
     r_lock = solve_distributed(cop, prob.b, x0, prob.x_star, action="gs",
                                sync="allgather", **kw)
     assert r_lock.lag is None
@@ -151,8 +151,13 @@ OVERLAP_SCRIPT = textwrap.dedent("""
     r_ovf = solve_distributed(cop, prob.b, x0, prob.x_star, action="gs",
                               sync="allgather", overlap=True, fused=True,
                               **kw)
-    assert jnp.array_equal(r_ov.x, r_ovf.x)          # fused overlap bitwise
-    assert jnp.array_equal(r_ov.err_sq, r_ovf.err_sq)
+    # fused overlap: the kernel sums each row in slot order where the scan
+    # uses einsum — f32 roundoff, inside the 1e-5 relative parity budget
+    # of tests/test_sweep_kernels.py
+    denom = float(jnp.linalg.norm(r_ov.x)) or 1.0
+    assert float(jnp.linalg.norm(r_ov.x - r_ovf.x)) / denom <= 1e-5
+    np.testing.assert_allclose(np.asarray(r_ov.err_sq),
+                               np.asarray(r_ovf.err_sq), rtol=1e-4, atol=1e-9)
     # a2a overlap reads the same slabs -> identical iterates
     r_a2a = solve_distributed(cop, prob.b, x0, prob.x_star, action="gs",
                               sync="a2a", overlap=True, **kw)

@@ -6,8 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (a_norm_sq, block_gs_solve, random_sparse_spd,
-                        rgs_general, rgs_solve, theory, to_unit_diagonal)
+from repro.core import (a_norm_sq, block_banded_spd, block_gs_solve,
+                        random_sparse_spd, rgs_general, rgs_solve, spd,
+                        theory, to_unit_diagonal)
 
 
 @pytest.fixture(scope="module")
@@ -82,3 +83,21 @@ def test_block_gs_converges(prob):
                          block=32, beta=0.9)
     assert float(res.resid[-1].max()) < 1e-2
     assert float(res.err_sq[-1].max()) < float(res.err_sq[0].max())
+
+
+@pytest.mark.parametrize("make", [
+    lambda: random_sparse_spd(320, row_nnz=8, seed=5),
+    lambda: block_banded_spd(256, block=32, bands=2, seed=5),
+], ids=["sparse", "banded"])
+def test_large_n_eigen_extremes_match_dense(make, monkeypatch):
+    """Above ``DENSE_EIG_MAX`` the generators take lam_min/lam_max from
+    float64 host Lanczos instead of a dense eigvalsh; both give the same
+    extremes to f32 rounding (ARPACK converges to machine precision)."""
+    dense = make()
+    monkeypatch.setattr(spd, "DENSE_EIG_MAX", 0)
+    lanczos = make()
+    np.testing.assert_array_equal(np.asarray(lanczos.A), np.asarray(dense.A))
+    np.testing.assert_allclose(float(lanczos.lam_min), float(dense.lam_min),
+                               rtol=1e-5)
+    np.testing.assert_allclose(float(lanczos.lam_max), float(dense.lam_max),
+                               rtol=1e-5)

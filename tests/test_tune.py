@@ -61,6 +61,20 @@ def test_table_load_drops_entries_on_version_mismatch(tmp_path):
     assert back.entries == {}   # fallback contract: unknown schema -> defaults
 
 
+def test_default_table_missing_vs_unloadable(tmp_path, monkeypatch):
+    """A missing table means "no table" (every seam defaults); a table
+    that exists but does not load is an error, not a silent default."""
+    monkeypatch.setenv("REPRO_TUNE_TABLE", str(tmp_path / "TUNE_none.json"))
+    monkeypatch.setattr(runtime, "_default_cache", runtime._UNSET)
+    assert runtime._load_default() is None
+    bad = tmp_path / "TUNE_bad.json"
+    bad.write_text("{not json")
+    monkeypatch.setenv("REPRO_TUNE_TABLE", str(bad))
+    monkeypatch.setattr(runtime, "_default_cache", runtime._UNSET)
+    with pytest.raises(ValueError):
+        runtime._load_default()
+
+
 def test_schedule_rejects_non_tristate_fused():
     with pytest.raises(ValueError):
         Schedule(num_iters=8, fused="always").validate()

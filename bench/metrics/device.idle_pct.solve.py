@@ -1,0 +1,1 @@
+from bench.readers import idle_pct as read  # noqa: F401

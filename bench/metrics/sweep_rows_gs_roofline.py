@@ -1,0 +1,1 @@
+from bench.readers import sweep_roofline_pct as read  # noqa: F401

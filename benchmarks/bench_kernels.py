@@ -38,10 +38,6 @@ from repro.core import (BlockBandedOp, CsrOp, EllOp, block_banded_spd,
                         random_sparse_spd)
 from repro.core.engine import solve_sequential
 from repro.kernels import ops, ref
-# The roofline terms come from repro.roofline's hardware model.  The peaks
-# are the TPU-v5e model; on CPU interpret mode the fractions are honest
-# near-zeros and the provenance stamp says why.
-from repro.roofline import HBM_BW, PEAK_FLOPS
 
 
 def run(n: int = 1024, block: int = 128, bands: int = 1, k: int = 64,
@@ -108,13 +104,9 @@ def run(n: int = 1024, block: int = 128, bands: int = 1, k: int = 64,
     skip_bytes = (skip_slots * (pv + pi) + skip_slots * k * 4 + pn.size * 4)
     skip_flops = 2 * pop.nnz_cost() * k
 
-    # Every layout row: modeled AI, min-of-N wall time, a check value
-    # against the dense oracle (uniform — a fast-but-wrong kernel fails
-    # loudly here and in the CI smoke job), AND the roofline view of the
-    # same byte/FLOP models: achieved GB/s on the modeled traffic plus the
-    # fraction of the roofline-predicted time actually achieved
-    # (max(bytes/HBM_BW, flops/PEAK_FLOPS) / wall — 1.0 means the kernel
-    # runs at the hardware model's limiting term).
+    # Every layout row: modeled AI and byte/FLOP counts, min-of-N wall
+    # time, and a check value against the dense oracle (uniform — a
+    # fast-but-wrong kernel fails loudly here and in the CI smoke job).
     layouts = {}
     for name, nbytes, flops, want, fn in (
         ("block_banded", bbmv_bytes, bbmv_flops, y_d,
@@ -133,15 +125,10 @@ def run(n: int = 1024, block: int = 128, bands: int = 1, k: int = 64,
         ai = flops / nbytes
         check = float(jnp.abs(fn() - want).max())
         wall = timed(fn, iters=repeats, stat="min")
-        gbps = nbytes / wall / 1e9
-        t_roof = max(nbytes / HBM_BW, flops / PEAK_FLOPS)
-        frac = t_roof / wall
         emit("bench_kernels", layout=name, ai_flops_per_byte=f"{ai:.1f}",
-             wall_us=f"{wall*1e6:.0f}", gbps=f"{gbps:.2f}",
-             roofline_frac=f"{frac:.4f}", check=f"{check:.2e}")
+             wall_us=f"{wall*1e6:.0f}", check=f"{check:.2e}")
         layouts[name] = {"ai_flops_per_byte": ai, "wall_us": wall * 1e6,
                          "model_bytes": int(nbytes), "model_flops": int(flops),
-                         "achieved_gbps": gbps, "roofline_frac": frac,
                          "check": check}
     layouts["csr_skip_empty"]["empty_panel_frac"] = empty_frac
     emit("bench_kernels", empty_panel_frac=f"{empty_frac:.2f}")

@@ -50,8 +50,8 @@ ROOT_MODULES = (
 )
 SCRIPT_DIRS = ("benchmarks", "examples")
 
-#: imports inside embedded subprocess-script strings (the forced-device
-#: benchmark pattern pipes `from repro import roofline` through a string)
+#: imports inside embedded subprocess-script strings (a forced-device
+#: script passes e.g. `from repro.kernels import sweep_csr` as a string)
 _STR_IMPORT = re.compile(
     r"^\s*(?:from\s+(repro(?:\.\w+)*)\s+import\s+([\w, ]+)"
     r"|import\s+(repro(?:\.\w+)*))", re.MULTILINE)

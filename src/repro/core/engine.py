@@ -319,7 +319,8 @@ def record_metrics(op, b, x, x_star, *, norm: str):
     if norm not in ("A", "euclid"):
         raise ValueError(norm)
     mv = getattr(op, "matvec_ref", op.matvec)
-    with jax.default_matmul_precision("highest"):
+    with jax.named_scope("repro.residual"), \
+            jax.default_matmul_precision("highest"):
         if x_star is None:
             err = jnp.full((x.shape[1],), jnp.nan, jnp.float32)
         else:
@@ -2149,6 +2150,9 @@ def _sparse_rk(op, b, x0, xs, key, *, mesh, axis, rounds, local_steps, beta,
 # Batched-RHS chunked entry (the serving layer's engine surface)
 # ---------------------------------------------------------------------------
 
+_span = jax.profiler.TraceAnnotation
+
+
 class BatchedSolveResult(NamedTuple):
     x: jax.Array          # (n, k) iterate after the last executed chunk
     resid: jax.Array      # (k,) ||b - A x||_2 at exit, per RHS column
@@ -2224,37 +2228,54 @@ def solve_batched(
     that chunk.  ``chunk_fn`` overrides the chunk executor (the serving
     layer passes its cached executable); the default builds
     ``sequential_chunk`` with this call's statics.
+
+    Under the JAX profiler the call shows as host spans on the calling
+    thread: ``repro.solve_batched`` around ``repro.draw_picks``, one
+    ``repro.chunk`` per chunk run — holding ``repro.picks_slice``,
+    ``repro.launch``, ``repro.resid_wait`` (the one device-to-host copy)
+    and ``repro.on_record`` — and ``repro.result``.
     """
     if num_iters <= 0:
         raise ValueError(f"num_iters must be > 0 (got {num_iters})")
     rec = resolve_record_every(num_iters, record_every)
     chunks = num_iters // rec
     k = b.shape[1]
-    if x0 is None:
-        n_x = op.shape[0] if action == "gs" else op.shape[1]
-        x0 = jnp.zeros((n_x, k), b.dtype)
-    tol_np = np.broadcast_to(np.asarray(tol, np.float32), (k,))
-    picks = draw_picks(op, action, key, num_iters, block=block)
-    if chunk_fn is None:
-        chunk_fn = functools.partial(sequential_chunk, action=action,
-                                     beta=beta, block=block, fused=fused)
-    x, resid = x0, None
-    rounds = np.zeros((k,), np.int32)
-    conv = np.zeros((k,), bool)
-    ran = 0
-    for c in range(chunks):
-        x, resid = chunk_fn(op, b, x, picks[c * rec:(c + 1) * rec])
-        ran = c + 1
-        newly = ~conv & (np.asarray(resid) <= tol_np)
-        rounds[newly] = ran
-        conv |= newly
-        go = on_record is None or bool(on_record(c, x, resid, conv.copy()))
-        if conv.all() or not go:
-            break
-    rounds = np.where(conv, rounds, ran).astype(np.int32)
-    return BatchedSolveResult(
-        x=x, resid=resid, rounds=jnp.asarray(rounds),
-        converged=jnp.asarray(conv), iters_run=ran * rec)
+    with _span("repro.solve_batched"):
+        if x0 is None:
+            n_x = op.shape[0] if action == "gs" else op.shape[1]
+            x0 = jnp.zeros((n_x, k), b.dtype)
+        tol_np = np.broadcast_to(np.asarray(tol, np.float32), (k,))
+        with _span("repro.draw_picks"):
+            picks = draw_picks(op, action, key, num_iters, block=block)
+        if chunk_fn is None:
+            chunk_fn = functools.partial(sequential_chunk, action=action,
+                                         beta=beta, block=block, fused=fused)
+        x, resid = x0, None
+        rounds = np.zeros((k,), np.int32)
+        conv = np.zeros((k,), bool)
+        ran = 0
+        for c in range(chunks):
+            with _span("repro.chunk"):
+                with _span("repro.picks_slice"):
+                    ps = picks[c * rec:(c + 1) * rec]
+                with _span("repro.launch"):
+                    x, resid = chunk_fn(op, b, x, ps)
+                ran = c + 1
+                with _span("repro.resid_wait"):
+                    resid_host = np.asarray(resid)
+                newly = ~conv & (resid_host <= tol_np)
+                rounds[newly] = ran
+                conv |= newly
+                with _span("repro.on_record"):
+                    go = on_record is None or bool(
+                        on_record(c, x, resid, conv.copy()))
+            if conv.all() or not go:
+                break
+        rounds = np.where(conv, rounds, ran).astype(np.int32)
+        with _span("repro.result"):
+            return BatchedSolveResult(
+                x=x, resid=resid, rounds=jnp.asarray(rounds),
+                converged=jnp.asarray(conv), iters_run=ran * rec)
 
 
 # ---------------------------------------------------------------------------

@@ -744,11 +744,12 @@ class CsrOp:
     def padded_rows(self) -> tuple[jax.Array, jax.Array]:
         """(m, row_cap) per-row value/column windows with global column ids
         — the slab-shardable form the distributed engine partitions."""
-        idx = self.row_start[:, None] + jnp.arange(self.row_cap)[None, :]
-        idx = jnp.minimum(idx, self.data.shape[0] - 1)
-        mask = jnp.arange(self.row_cap)[None, :] < self.row_nnz[:, None]
-        vals = jnp.where(mask, self.data[idx], 0.0)
-        cols = jnp.where(mask, self.indices[idx], 0)
+        with jax.named_scope("repro.row_windows"):
+            idx = self.row_start[:, None] + jnp.arange(self.row_cap)[None, :]
+            idx = jnp.minimum(idx, self.data.shape[0] - 1)
+            mask = jnp.arange(self.row_cap)[None, :] < self.row_nnz[:, None]
+            vals = jnp.where(mask, self.data[idx], 0.0)
+            cols = jnp.where(mask, self.indices[idx], 0)
         return vals, cols
 
     def gs_sweep(self, b, x, picks, *, beta: float = 1.0, write_base=0,

@@ -13,8 +13,8 @@ step), so successive steps see each other's updates — sequential randomized
 block GS, the tau = 0 best case of the paper's analysis.  The A-band panel
 for the chosen row streams HBM->VMEM via the scalar-prefetch index map: the
 per-step HBM traffic is exactly the (2*bands+1) tiles + nothing else, which
-is what makes the solver's memory-roofline fraction in EXPERIMENTS.md §Perf
-attainable (no score/convert spills — contrast the unfused jnp path).
+is what makes the memory roofline attainable (no score/convert spills —
+contrast the unfused jnp path).
 
 Validity masking: border blocks whose band column falls outside the matrix
 contribute zero (the tiles are zero-padded by ``pack_bands``), so no branch

@@ -115,28 +115,34 @@ def banded_rk_sweep(A_bands, b, rn, xw, dw, picks, gates, *, block, bands,
                             interpret=_interp(interpret))
 
 
+# The ``repro.sweep`` scope wraps the jitted kernels from outside: inside
+# their jit it would rename the Pallas custom call after itself, and the
+# benchmark finds the kernel in a trace by its name (``sweep_rows_gs.N``).
 def sweep_rows_gs(vals, cols, b, x, picks, *, beta=1.0, write_base=0,
                   interpret=None):
     """Fused coordinate-GS sweep over padded sparse rows (CSR/ELL).
     ``write_base`` offsets writes by a (possibly traced) slab offset —
     the distributed local phase's global row base."""
-    return _sweep_rows_gs(vals, cols, b, x, picks, beta=beta,
-                          write_base=write_base,
-                          interpret=_interp(interpret))
+    with jax.named_scope("repro.sweep"):
+        return _sweep_rows_gs(vals, cols, b, x, picks, beta=beta,
+                              write_base=write_base,
+                              interpret=_interp(interpret))
 
 
 def sweep_rows_rk(vals, cols, b, rn, x, picks, *, beta=1.0, interpret=None):
     """Fused Kaczmarz sweep over padded sparse rows (CSR/ELL)."""
-    return _sweep_rows_rk(vals, cols, b, rn, x, picks, beta=beta,
-                          interpret=_interp(interpret))
+    with jax.named_scope("repro.sweep"):
+        return _sweep_rows_rk(vals, cols, b, rn, x, picks, beta=beta,
+                              interpret=_interp(interpret))
 
 
 def sweep_rows_rk_delta(vals, cols, b, rn, x, d, picks, *, beta=1.0,
                         interpret=None):
     """Fused two-carry (replica, round-delta) Kaczmarz sweep over padded
     sparse rows — the distributed local phase of ``sparse_rk``."""
-    return _sweep_rows_rk_delta(vals, cols, b, rn, x, d, picks, beta=beta,
-                                interpret=_interp(interpret))
+    with jax.named_scope("repro.sweep"):
+        return _sweep_rows_rk_delta(vals, cols, b, rn, x, d, picks,
+                                    beta=beta, interpret=_interp(interpret))
 
 
 __all__ = [

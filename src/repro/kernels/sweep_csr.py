@@ -153,8 +153,15 @@ def resident_sweep(step, prefetch, row_streams, carries, *, interpret):
 def _row_windows(vals, cols):
     """(m, 1, width) f32 values / int32 column ids for the SMEM row stream."""
     m, width = vals.shape
-    return (vals.astype(jnp.float32).reshape(m, 1, width),
-            cols.astype(jnp.int32).reshape(m, 1, width))
+    with jax.named_scope("repro.sweep_operands"):
+        return (vals.astype(jnp.float32).reshape(m, 1, width),
+                cols.astype(jnp.int32).reshape(m, 1, width))
+
+
+def _row_stream(a):
+    """``(m, w)`` -> the ``(m, 1, w)`` view a per-row stream reads."""
+    with jax.named_scope("repro.sweep_operands"):
+        return a.reshape(a.shape[0], 1, -1)
 
 
 def _row_dot(vals_ref, cols_ref, xv, width: int):
@@ -215,7 +222,7 @@ def sweep_rows_gs(
 
     (x,) = resident_sweep(
         step, (picks.astype(jnp.int32), base),
-        [(vw, pltpu.SMEM), (cw, pltpu.SMEM), (b.reshape(m, 1, k), None)],
+        [(vw, pltpu.SMEM), (cw, pltpu.SMEM), (_row_stream(b), None)],
         [x], interpret=interpret)
     return x
 
@@ -239,8 +246,8 @@ def _rk_sweep(vals, cols, b, rn, carries, picks, *, beta, interpret):
     step = functools.partial(_rk_step, beta=beta, width=width)
     return resident_sweep(
         step, (picks.astype(jnp.int32),),
-        [(vw, pltpu.SMEM), (cw, pltpu.SMEM), (b.reshape(m, 1, k), None),
-         (rn.astype(jnp.float32).reshape(m, 1, 1), pltpu.SMEM)],
+        [(vw, pltpu.SMEM), (cw, pltpu.SMEM), (_row_stream(b), None),
+         (_row_stream(rn.astype(jnp.float32)), pltpu.SMEM)],
         carries, interpret=interpret)
 
 

@@ -433,6 +433,32 @@ class EllOp:
         return out.at[jnp.arange(n)[:, None], self.cols].add(self.vals)
 
 
+def _slot_windows(flat: jax.Array, start: jax.Array,
+                  width: int) -> jax.Array:
+    """Rows ``flat[start[i] : start[i] + width]``, shape (len(start), width);
+    every window must lie inside ``flat``.
+
+    The TPU compiler keeps a gather of whole rows as one gather, but turns
+    a gather of unaligned 1-D slices into a loop with one trip per slice,
+    and a scalar gather per slot costs a few ns a slot.  So view ``flat``
+    as rows of ``g`` (a power of two >= width) slots: each window lies in
+    rows ``start // g`` and the one after, which one gather of m indices
+    fetches side by side; log2(g) selects between static slices then shift
+    each row left by ``start % g``.  Data movement only: bitwise exact.
+    """
+    g = 1 << (width - 1).bit_length()
+    rows = -(-flat.shape[0] // g) + 1
+    t = jnp.pad(flat, (0, rows * g - flat.shape[0])).reshape(rows, g)
+    w = jnp.concatenate([t[:-1], t[1:]], axis=1)[start // g]
+    off = start % g
+    k = g // 2
+    while k:
+        w = jnp.where((off & k)[:, None] != 0, w[:, k:],
+                      w[:, :w.shape[1] - k])
+        k //= 2
+    return w[:, :width]
+
+
 @register_pytree_node_class
 class CsrOp:
     """General compressed-sparse-row operator, panel-aligned for the TPU.
@@ -743,14 +769,14 @@ class CsrOp:
 
     def padded_rows(self) -> tuple[jax.Array, jax.Array]:
         """(m, row_cap) per-row value/column windows with global column ids
-        — the slab-shardable form the distributed engine partitions."""
+        — the slab-shardable form the distributed engine partitions.
+        One row gather of m indices per array (``_slot_windows``), not
+        one scalar gather per slot."""
         with jax.named_scope("repro.row_windows"):
-            idx = self.row_start[:, None] + jnp.arange(self.row_cap)[None, :]
-            idx = jnp.minimum(idx, self.data.shape[0] - 1)
             mask = jnp.arange(self.row_cap)[None, :] < self.row_nnz[:, None]
-            vals = jnp.where(mask, self.data[idx], 0.0)
-            cols = jnp.where(mask, self.indices[idx], 0)
-        return vals, cols
+            vals = _slot_windows(self.data, self.row_start, self.row_cap)
+            cols = _slot_windows(self.indices, self.row_start, self.row_cap)
+            return jnp.where(mask, vals, 0.0), jnp.where(mask, cols, 0)
 
     def gs_sweep(self, b, x, picks, *, beta: float = 1.0, write_base=0,
                  interpret=None) -> jax.Array:

@@ -61,6 +61,37 @@ def test_chunk_hlo_carries_program_scopes(prob, fmt, want):
         assert "repro.row_windows" not in found
 
 
+def _gathers(text, scope):
+    """(start indices, slots a slice) of each gather whose op_name holds
+    ``scope``: the indices are the output's dims outside ``offset_dims``."""
+    out = []
+    for line in text.splitlines():
+        if f"/{scope}/" not in line:
+            continue
+        g = re.search(r"= \w+\[([\d,]*)\]\S* gather\(.*?offset_dims=\{([\d,]*)\}"
+                      r".*?slice_sizes=\{([\d,]*)\}", line)
+        if g:
+            dims = [int(d) for d in g[1].split(",") if d]
+            offset = {int(d) for d in g[2].split(",") if d}
+            out.append((int(np.prod([d for i, d in enumerate(dims)
+                                     if i not in offset])),
+                        int(np.prod([int(s) for s in g[3].split(",")]))))
+    return out
+
+
+@pytest.mark.parametrize("storage", [None, "bfloat16"])
+def test_row_windows_gather_one_slice_per_row(prob, storage):
+    """The chunk builds the row windows with gathers of m start indices,
+    each fetching at least ``row_cap`` slots, never with one scalar
+    gather a slot (m * row_cap indices)."""
+    op = _op("csr", prob.A, storage_dtype=storage)
+    gathers = _gathers(_chunk_hlo(op, prob.b), "repro.row_windows")
+    assert gathers
+    for starts, slots in gathers:
+        assert starts == N and slots >= op.row_cap
+        assert starts != N * op.row_cap
+
+
 def _events(trace_dir):
     from jax.profiler import ProfileData
     path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))[-1]

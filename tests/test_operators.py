@@ -333,6 +333,52 @@ def test_storage_dtype_layout(sparse_prob):
         as_operator(A, "csr", storage_dtype="float16")
 
 
+def _slot_gather_windows(op):
+    """Oracle for ``CsrOp.padded_rows``: the (m, row_cap) windows built by
+    one scalar gather per slot."""
+    idx = op.row_start[:, None] + jnp.arange(op.row_cap)[None, :]
+    idx = jnp.minimum(idx, op.data.shape[0] - 1)
+    mask = jnp.arange(op.row_cap)[None, :] < op.row_nnz[:, None]
+    return (jnp.where(mask, op.data[idx], 0.0),
+            jnp.where(mask, op.indices[idx], 0))
+
+
+def _edge_rows_matrix():
+    """40 x 40 rows with 0 and row_cap nonzeros.  Packed with ``lane=1``,
+    the last panel is the fullest, so its empty last row starts at
+    ``num_panels * W`` and its window is exactly the slack at the end."""
+    rng = np.random.default_rng(11)
+    A = np.zeros((40, 40), np.float32)
+    counts = rng.integers(1, 6, 40)
+    counts[[0, 13, 39]] = 0
+    counts[[5, 32, 38]] = 12
+    for r, c in enumerate(counts):
+        A[r, rng.choice(40, c, replace=False)] = rng.standard_normal(c)
+    return A
+
+
+@pytest.mark.parametrize("call", ["eager", "jit"])
+@pytest.mark.parametrize("layout", ["packed", "permuted"])
+@pytest.mark.parametrize("storage", [None, "bfloat16"])
+def test_csr_padded_rows_match_slot_gather(storage, layout, call):
+    from repro.core import partition as pt
+    op = CsrOp.from_dense(_edge_rows_matrix(), lane=1, storage_dtype=storage)
+    assert op.row_cap == 12
+    assert int(op.row_start[-1]) + op.row_cap == op.data.shape[0]
+    if layout == "permuted":
+        op = pt.permute_rows(op, pt.balanced_row_permutation(op, 4),
+                             symmetric=True)
+    got = (op.padded_rows() if call == "eager"
+           else jax.jit(lambda o: o.padded_rows())(op))
+    want = _slot_gather_windows(op)
+    assert got[0].dtype == op.data.dtype and got[1].dtype == op.indices.dtype
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape == (40, 12)
+        np.testing.assert_array_equal(g.view(f"u{g.itemsize}"),
+                                      w.view(f"u{w.itemsize}"))
+
+
 def test_shard_specs_and_structure(banded_prob, sparse_prob):
     """Metadata the conformance grid does not pin: shard specs and the
     banded tile geometry."""

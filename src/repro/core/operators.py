@@ -721,9 +721,12 @@ class CsrOp:
         return self._panel_nnz_cache
 
     def matvec_ref(self, x: jax.Array) -> jax.Array:
+        """``A @ x`` from the ``padded_rows()`` windows: a gather of
+        ``x[cols]`` and an f32 reduction over the window axis, the ELL
+        reference.  Every slot already sits in its output row, so no
+        scatter-add; inside a fused chunk the windows are the sweep's."""
         from repro.kernels import ref
-        return ref.spmv_csr_ref(self.data, self.indices, self.row_id, x,
-                                m=self._shape[0])
+        return ref.spmv_ell_ref(*self.padded_rows(), x)
 
     def _row_window(self, r):
         """Row ``r``'s values/columns as a fixed Θ(row_cap) masked window."""

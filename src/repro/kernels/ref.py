@@ -22,22 +22,9 @@ def bbmv_ref(A_dense, x):
 
 
 def spmv_ell_ref(vals, cols, x):
-    """Values up-cast to f32 before contracting, matching the kernel's
-    f32 accumulation (identity for f32 storage)."""
-    n, width = vals.shape
+    """y = A @ x from (rows, width) value/column windows: a gather of
+    ``x[cols]`` reduced over the window axis (``EllOp``'s storage, and
+    ``CsrOp.padded_rows()``).  Values up-cast to f32 before contracting,
+    matching the kernels' f32 accumulation (identity for f32 storage)."""
     return jnp.einsum("nw,nwk->nk", vals.astype(jnp.float32),
                       x[cols]).astype(x.dtype)
-
-
-def spmv_csr_ref(data, indices, row_id, x, *, m):
-    """y = A @ x from flat CSR triples via a true segment sum.
-
-    Padding slots carry data == 0 (and point at column 0 / row 0), so they
-    contribute nothing regardless of where they scatter.  Values up-cast
-    to f32 (identity for f32 storage) so low-precision operators still
-    accumulate in f32.
-    """
-    contrib = data.astype(jnp.float32)[:, None] * x[indices]
-    return jax.ops.segment_sum(contrib, row_id,
-                               num_segments=m).astype(x.dtype)
-

@@ -13,6 +13,8 @@ jax+pytest); when hypothesis is installed the same checker fuzzes over
 random shapes/sparsity/seeds.  The engine-facing tests (dispatch, pytree
 flattening, sequential format-genericity) ride below unchanged.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -377,6 +379,88 @@ def test_csr_padded_rows_match_slot_gather(storage, layout, call):
         assert g.dtype == w.dtype and g.shape == w.shape == (40, 12)
         np.testing.assert_array_equal(g.view(f"u{g.itemsize}"),
                                       w.view(f"u{w.itemsize}"))
+
+
+def _ragged_matrix(m, n):
+    """m x n rows of 0 to 9 nonzeros, rows 0 and m // 2 empty."""
+    rng = np.random.default_rng(m * 100 + n)
+    A = np.zeros((m, n), np.float32)
+    counts = rng.integers(1, 10, m)
+    counts[[0, m // 2]] = 0
+    for r, c in enumerate(counts):
+        A[r, rng.choice(n, c, replace=False)] = rng.standard_normal(c)
+    return A
+
+
+def _f32_dot_bound(A, x, terms):
+    """Float32 error bound of a ``terms``-long dot product, row by row:
+    each product and each add rounds once, so the error stays under
+    ``(terms + 1) * eps * (|A| @ |x|)`` (Higham, Thm. 3.1 to first order)."""
+    return (terms + 1) * np.finfo(np.float32).eps * (np.abs(A) @ np.abs(x))
+
+
+@pytest.mark.parametrize("call", ["eager", "jit"])
+@pytest.mark.parametrize("storage", [None, "bfloat16"])
+@pytest.mark.parametrize("k", [1, 3])
+@pytest.mark.parametrize("shape", [(40, 40), (40, 24), (24, 40)])
+def test_csr_matvec_ref_is_window_reduce(shape, k, storage, call):
+    """``CsrOp.matvec_ref`` is the ELL reference over ``padded_rows()``:
+    bitwise that form, and the float64 ``A @ x`` to float32 rounding, on
+    ragged rows with empty ones, square and rectangular, eager and jitted."""
+    from repro.kernels import ref
+    m, n = shape
+    op = CsrOp.from_dense(_ragged_matrix(m, n), storage_dtype=storage)
+    if storage == "bfloat16":
+        assert op.indices.dtype == jnp.int16
+    x = jax.random.normal(jax.random.key(k), (n, k), jnp.float32)
+
+    def mv(o, v):
+        return o.matvec_ref(v)
+
+    def ell(o, v):
+        return ref.spmv_ell_ref(*o.padded_rows(), v)
+
+    if call == "jit":
+        mv, ell = jax.jit(mv), jax.jit(ell)
+    got = np.asarray(mv(op, x))
+    assert got.dtype == np.float32 and got.shape == (m, k)
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  np.asarray(ell(op, x)).view(np.uint32))
+
+    # The stored values exactly (bf16 storage rounds them), in float64.
+    A64 = np.asarray(op.to_dense(), np.float64)
+    x64 = np.asarray(x, np.float64)
+    err = np.abs(got - A64 @ x64)
+    assert (err <= _f32_dot_bound(A64, x64, op.row_cap)).all()
+    assert (got[[0, m // 2]] == 0).all()          # empty rows read zero
+
+
+def test_csr_record_metrics_match_float64(sparse_prob):
+    """``record_metrics`` on a ``CsrOp`` (through its window-reduce
+    ``matvec_ref``) gives the float64 residual and A-norm error."""
+    from repro.core.engine import record_metrics
+    op = CsrOp.from_dense(sparse_prob.A)
+    xs = sparse_prob.x_star
+    x = xs + 1e-2 * jax.random.normal(jax.random.key(3), xs.shape)
+    err, resid = jax.jit(functools.partial(record_metrics, norm="A"))(
+        op, sparse_prob.b, x, xs)
+    A64, b64 = (np.asarray(v, np.float64) for v in (sparse_prob.A,
+                                                    sparse_prob.b))
+    x64, e64 = np.asarray(x, np.float64), np.asarray(x - xs, np.float64)
+    r64 = b64 - A64 @ x64
+    eps = np.finfo(np.float32).eps
+    # Each entry of b − Ax carries its row's dot bound, one more rounding
+    # for the subtraction, and b's own; the norm over m rows rounds m times.
+    rerr = _f32_dot_bound(A64, x64, op.row_cap + 1) + eps * np.abs(b64)
+    rn64 = np.linalg.norm(r64, axis=0)
+    tol = np.linalg.norm(rerr, axis=0) + op.shape[0] * eps * rn64
+    assert (np.abs(np.asarray(resid) - rn64) <= tol).all()
+    # eᵀAe: each entry of Ae carries the dot bound; the outer sum over n
+    # rows adds n roundings of the |e|ᵀ|A||e| scale.
+    want = np.einsum("nk,nk->k", e64, A64 @ e64)
+    scale = np.einsum("nk,nk->k", np.abs(e64), np.abs(A64) @ np.abs(e64))
+    tol = (op.row_cap + op.shape[0] + 2) * eps * scale
+    assert (np.abs(np.asarray(err) - want) <= tol).all()
 
 
 def test_shard_specs_and_structure(banded_prob, sparse_prob):

@@ -1,15 +1,17 @@
 """Compile-only rehearsal: the fused sweep kernels of the main solve path,
 compiled by the TPU compiler for a described (not attached) v5e chip at
 the reference scale chip_smoke.py runs — n = 16384 unknowns, k = 51
-right-hand sides.  A tiling, SMEM or VMEM refusal shows up here, on the
-CPU, before it costs a chip run.  Nothing executes, so these tests say
-nothing about results or speed.
+right-hand sides, and one record chunk of the fused engine around them.
+A tiling, SMEM or VMEM refusal shows up here, on the CPU, before it costs
+a chip run.  Nothing executes, so these tests say nothing about results or
+speed.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and under several pytest
 workers every worker imports this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,3 +94,32 @@ def test_fused_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in lowered.as_text()   # a Pallas kernel, not XLA
     compiled = lowered.compile()   # raises what the chip's compiler refuses
     assert compiled.memory_analysis() is not None
+
+
+#: (k, row_cap, panel_width) of the two benchmark systems at n = 16384:
+#: random_sparse_spd's k = 51 system and HPCG's 27-point stencil, k = 1
+CHUNKS = [(51, 52, 384), (1, 27, 256)]
+
+
+@pytest.mark.parametrize("k,cap,width", CHUNKS)
+def test_fused_chunk_residual_has_no_scatter(k, cap, width, one_chip):
+    """One record chunk of the fused engine over a ``CsrOp`` (the sweep,
+    then ``record_metrics``' residual) compiles with no scatter: the
+    residual reduces the row windows, and shares the sweep's two
+    row-window gathers, so the chunk gathers three times (values, ids,
+    ``x[cols]``)."""
+    from repro.core import engine
+    from repro.core.operators import CsrOp
+    flat = (N // 8) * width + cap
+    data, ids, rows, start, nnz = _shapes(
+        one_chip, ((flat,), f32), ((flat,), i32), ((flat,), i32), ((N,), i32),
+        ((N,), i32))
+    op = CsrOp(data, ids, rows, start, nnz, shape=(N, N), nnz=N * cap,
+               row_cap=cap, rows_per_panel=8, panel_width=width)
+    b, x, picks = _shapes(one_chip, ((N, k), f32), ((N, k), f32), ((N,), i32))
+    text = engine._sequential_fused_impl.lower(
+        op, b, x, None, action="gs", key=jax.random.key(0), num_iters=N,
+        beta=1.0, block=1, record_every=0, picks=picks).compile().as_text()
+    ops = re.findall(r"= [^=]*? (gather|scatter)\(", text)
+    assert ops.count("scatter") == 0
+    assert ops.count("gather") == 3
